@@ -20,7 +20,8 @@ def main():
     ap.add_argument("--bdcn-size", type=int, default=64)
     ap.add_argument("--backend", default=None,
                     help="GemmPolicy backend for the approximate GEMMs "
-                         "(default approx_lut, the paper's table model)")
+                         "(default approx_delta, bit-identical to the "
+                         "paper's table model approx_lut)")
     args = ap.parse_args()
     paper_edge = {2: (30.45, 0.910), 4: (20.51, 0.894), 6: (12.76, 0.678),
                   8: (11.41, 0.651)}

@@ -7,8 +7,9 @@ exact-arithmetic output of the identical pipeline.
 The convolution kernel is a fixed weight: it is prepared once per k
 (``gemm.prepare_weights``) so the weight-stationary backends (``approx_delta``
 rank-r factor, ``approx_onehot`` T_B) amortize their precompute across every
-im2col row. The default ``approx_lut`` backend reproduces the paper's
-product-table model bit-for-bit.
+im2col row. The default ``approx_delta`` backend reproduces the paper's
+product-table model (``approx_lut``) bit-for-bit and runs on the TPU, where
+the table gather has no kernel.
 """
 from __future__ import annotations
 
@@ -22,7 +23,7 @@ from . import images
 LAPLACIAN = np.array([[0, 1, 0], [1, -4, 1], [0, 1, 0]], dtype=np.int32)
 LAPLACIAN8 = np.array([[1, 1, 1], [1, -8, 1], [1, 1, 1]], dtype=np.int32)
 
-DEFAULT_BACKEND = "approx_lut"
+DEFAULT_BACKEND = "approx_delta"
 
 
 def im2col(img: np.ndarray, kh: int = 3, kw: int = 3) -> np.ndarray:

@@ -204,8 +204,9 @@ def _correction(a_u: jnp.ndarray, b_u: jnp.ndarray, fac: DeltaFactors) -> jnp.nd
                                       fac.acc_bits, fac.rank)
     f_a = jnp.take(f_dev, a_u, axis=0)                        # (M, K, r)
     g_b = jnp.take(g_dev, b_u, axis=1)                        # (r, K, N)
-    return (f_a.reshape(m, kd * fac.rank)
-            @ jnp.transpose(g_b, (1, 0, 2)).reshape(kd * fac.rank, n))
+    return jnp.matmul(f_a.reshape(m, kd * fac.rank),
+                      jnp.transpose(g_b, (1, 0, 2)).reshape(kd * fac.rank, n),
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 def defect_gather_matmul(a_u: jnp.ndarray, b_u: jnp.ndarray,
@@ -446,11 +447,13 @@ def _delta_prepared_impl(x, w_u, w_s, factor, gather_tab, defect_flat, *,
         if side == "right":
             m, kd = x_u.shape
             corr = jax.lax.dot_general(                 # (r,M,K) x (K,r,N)
-                mov.reshape(rank, m, kd), factor, (((0, 2), (1, 0)), ((), ())))
+                mov.reshape(rank, m, kd), factor, (((0, 2), (1, 0)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST)
         else:
             kd, n = x_u.shape
             corr = jax.lax.dot_general(                 # (M,K,r) x (r,K,N)
-                factor, mov.reshape(rank, kd, n), (((1, 2), (1, 0)), ((), ())))
+                factor, mov.reshape(rank, kd, n), (((1, 2), (1, 0)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST)
     else:
         corr = jnp.zeros(base.shape, jnp.float32)
     if use_defect:

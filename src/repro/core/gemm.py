@@ -67,6 +67,7 @@ class GemmPolicy:
     so ``resolve`` falls back to ``approx_lut`` for that layer — bit-
     identical output, strictly less work. `prepare_weights` supplies the
     (out_width, delta_rank) hints; without hints resolution is unchanged.
+    On a TPU the fallback is off: the gather path has no TPU lowering.
 
     ``guard`` selects the ABFT fault-detection mode (core/abft.py):
     ``'none'`` (default, zero overhead), ``'detect'`` (checksum every GEMM,
@@ -98,7 +99,8 @@ class GemmPolicy:
             choice = self.backend
         if (choice == "approx_delta" and self.delta_adaptive
                 and out_width is not None and delta_rank is not None
-                and delta_rank > out_width):
+                and delta_rank > out_width
+                and jax.default_backend() != "tpu"):
             return "approx_lut"
         return choice
 

@@ -95,5 +95,6 @@ def onehot_matmul(a, t_b, *, n_bits: int = 8):
     onehot = jax.nn.one_hot(a_u, span, dtype=jnp.bfloat16)  # (M, K, V)
     out = jax.lax.dot_general(                              # exact MXU matmul
         onehot.reshape(m, kk * span), t_b, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
     return out.astype(jnp.int32)

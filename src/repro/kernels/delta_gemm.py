@@ -4,22 +4,21 @@ MXU-resident form of the approximate systolic array (see core/error_delta.py):
 each (bm, bn, bk) block computes
 
     o += dot_i8(a, b)                          # exact PE array == the MXU
-       + round( sum_r f_r[a_u] @ g_r[b_u] )    # rank-r float32 correction
+       + round( sum_r F_r[a] @ G_r[b] )        # rank-r float32 correction
 
-in one kernel — both contractions stream the same A/B blocks, so the
-correction costs no extra HBM traffic, and the per-element f/g lookups are
-O(bm*bk + bk*bn) gathers into VMEM-resident 256-entry vectors (vs the
-O(bm*bn*bk) table gathers of approx_gemm.py).
+in one kernel. The per-element factor lookups ``F_r = f[a_u, r]`` and
+``G_r = g[r, b_u]`` are gathered by the wrapper (`ops.approx_delta_matmul`)
+into ``(r, M, K)`` / ``(r, K, N)`` float32 operands that stream block by
+block beside A and B: Mosaic lowers no 1-D vector gather, so the kernel
+holds only MXU contractions. The correction dots run at
+``Precision.HIGHEST`` (full float32 on the MXU) — a bf16 pass would lose the
+integer correction.
 
 Rounding happens per K-block: the true block correction is an integer (a sum
 of integer E entries), and the float32 noise per block is ~1e-2, so each
 rounded block is exact and the int32 accumulation across the K grid introduces
 no drift — the kernel is bit-identical to the gather path at the exact rank
 for arbitrary K.
-
-VMEM budget: f and g are (span * rank) float32 each — 21 KiB at the k=6 rank
-of 21 — held resident across the whole grid like approx_gemm's table, but
-~12x smaller.
 """
 from __future__ import annotations
 
@@ -47,8 +46,12 @@ def tpu_contract(m: int, n: int, k: int, *, rank: int, span: int = 256,
     """
     from repro.analysis import contracts as C
     bm, bn, bk = min(bm, m), min(bn, n), min(bk, k)
-    tab = span * max(rank, 1)
     grid = (-(-m // bm), -(-n // bn), -(-k // bk))
+    factors = () if not rank else (
+        C.OperandSpec("f_a", (rank, m, k), "float32", (rank, bm, bk),
+                      lambda i, j, kk: (0, i, kk)),
+        C.OperandSpec("g_b", (rank, k, n), "float32", (rank, bk, bn),
+                      lambda i, j, kk: (0, kk, j)))
     return C.KernelGeometry(
         kernel="kernels.delta_gemm.delta_matmul_fused",
         grid=grid,
@@ -57,10 +60,7 @@ def tpu_contract(m: int, n: int, k: int, *, rank: int, span: int = 256,
                           lambda i, j, kk: (i, kk)),
             C.OperandSpec("b", (k, n), "int8", (bk, bn),
                           lambda i, j, kk: (kk, j)),
-            C.OperandSpec("f", (tab,), "float32", (tab,),
-                          lambda i, j, kk: (0,)),
-            C.OperandSpec("g", (tab,), "float32", (tab,),
-                          lambda i, j, kk: (0,)),
+            *factors,
             C.OperandSpec("o", (m, n), "int32", (bm, bn),
                           lambda i, j, kk: (i, j)),
         ),
@@ -68,46 +68,43 @@ def tpu_contract(m: int, n: int, k: int, *, rank: int, span: int = 256,
     )
 
 
-def _kernel(a_ref, b_ref, f_ref, g_ref, o_ref, *, rank: int, span: int):
+def _kernel(a_ref, b_ref, *refs, rank: int):
+    *factor_refs, o_ref = refs
     k_idx = pl.program_id(2)
 
     @pl.when(k_idx == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    a_blk = a_ref[...]          # (bm, bk) int8 signed values (sign-extended patterns)
-    b_blk = b_ref[...]          # (bk, bn)
     # exact base: int8 x int8 -> int32 on the MXU
-    acc = jax.lax.dot_general(a_blk, b_blk, (((1,), (0,)), ((), ())),
+    acc = jax.lax.dot_general(a_ref[...], b_ref[...], (((1,), (0,)), ((), ())),
                               preferred_element_type=jnp.int32)
     if rank:
-        mask = span - 1
-        a_u = a_blk.astype(jnp.int32) & mask
-        b_u = b_blk.astype(jnp.int32) & mask
-        f = f_ref[...]          # (span*rank,) f[v*rank + r]
-        g = g_ref[...]          # (rank*span,) g[r*span + v]
+        fa_ref, gb_ref = factor_refs
         corr = jnp.zeros(acc.shape, jnp.float32)
         for rr in range(rank):  # static unroll: rank MXU dots per block
-            f_a = jnp.take(f, a_u * rank + rr, axis=0)      # (bm, bk)
-            g_b = jnp.take(g, b_u + rr * span, axis=0)      # (bk, bn)
-            corr += jax.lax.dot_general(f_a, g_b, (((1,), (0,)), ((), ())),
-                                        preferred_element_type=jnp.float32)
+            corr += jax.lax.dot_general(
+                fa_ref[rr], gb_ref[rr], (((1,), (0,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)
         acc += jnp.round(corr).astype(jnp.int32)
     o_ref[...] += acc
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("rank", "span", "bm", "bn", "bk", "interpret"))
-def delta_matmul_fused(a_s: jnp.ndarray, b_s: jnp.ndarray, f_flat: jnp.ndarray,
-                       g_flat: jnp.ndarray, *, rank: int, span: int = 256,
+@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
+def delta_matmul_fused(a_s: jnp.ndarray, b_s: jnp.ndarray,
+                       f_a: jnp.ndarray | None = None,
+                       g_b: jnp.ndarray | None = None, *,
                        bm: int = DEFAULT_BM, bn: int = DEFAULT_BN,
                        bk: int = DEFAULT_BK,
                        interpret: bool = False) -> jnp.ndarray:
     """(M, K) x (K, N) -> (M, N) int32 via base matmul + rank-r correction.
 
     a_s/b_s hold *signed* operand values (int8-representable; ops.py converts
-    bit patterns); f_flat/g_flat come from error_delta.factor_tables_jnp.
-    Shapes must be block multiples (ops.approx_delta_matmul pads).
+    bit patterns). f_a ``(r, M, K)`` / g_b ``(r, K, N)`` are the gathered
+    float32 correction factors (`ops.approx_delta_matmul`); without them the
+    kernel is the exact int8 base alone. Shapes must be block multiples
+    (ops.approx_delta_matmul pads).
     """
     m, k = a_s.shape
     k2, n = b_s.shape
@@ -115,24 +112,23 @@ def delta_matmul_fused(a_s: jnp.ndarray, b_s: jnp.ndarray, f_flat: jnp.ndarray,
     bm, bn, bk = min(bm, m), min(bn, n), min(bk, k)
     assert m % bm == 0 and n % bn == 0 and k % bk == 0, (
         f"shapes ({m},{k})x({k},{n}) not multiples of blocks ({bm},{bn},{bk})")
-    tab = span * max(rank, 1)
-    assert f_flat.shape == (tab,) and g_flat.shape == (tab,), (
-        f_flat.shape, g_flat.shape, rank, span)
-    grid = (m // bm, n // bn, k // bk)
-    kern = functools.partial(_kernel, rank=rank, span=span)
+    rank = 0 if f_a is None else f_a.shape[0]
+    in_specs = [pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
+                pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j))]
+    args = [a_s.astype(jnp.int8), b_s.astype(jnp.int8)]
+    if rank:
+        assert f_a.shape == (rank, m, k) and g_b.shape == (rank, k, n), (
+            f_a.shape, g_b.shape, a_s.shape, b_s.shape)
+        in_specs += [pl.BlockSpec((rank, bm, bk), lambda i, j, kk: (0, i, kk)),
+                     pl.BlockSpec((rank, bk, bn), lambda i, j, kk: (0, kk, j))]
+        args += [f_a.astype(jnp.float32), g_b.astype(jnp.float32)]
     return pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((tab,), lambda i, j, kk: (0,)),    # resident f
-            pl.BlockSpec((tab,), lambda i, j, kk: (0,)),    # resident g
-        ],
+        functools.partial(_kernel, rank=rank),
+        grid=(m // bm, n // bn, k // bk),
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(a_s.astype(jnp.int8), b_s.astype(jnp.int8),
-      f_flat.astype(jnp.float32), g_flat.astype(jnp.float32))
+    )(*args)

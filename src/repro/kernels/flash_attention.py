@@ -78,12 +78,8 @@ def _kernel(kvl_ref, q_ref, k_ref, v_ref, o_ref, *, h: int, bq: int, bk: int,
 
     def body(kb, carry):
         acc, m, l = carry
-        # index the leading block dim with a length-1 slice: pl.load rejects
-        # bare int indices on this jax version
-        k_blk = pl.load(k_ref, (pl.dslice(0, 1), pl.dslice(kb * bk, bk),
-                                slice(None)))[0].astype(jnp.float32)
-        v_blk = pl.load(v_ref, (pl.dslice(0, 1), pl.dslice(kb * bk, bk),
-                                slice(None)))[0].astype(jnp.float32)
+        k_blk = k_ref[0, pl.ds(kb * bk, bk), :].astype(jnp.float32)
+        v_blk = v_ref[0, pl.ds(kb * bk, bk), :].astype(jnp.float32)
         s = jax.lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         if softcap > 0:

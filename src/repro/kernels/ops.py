@@ -2,10 +2,10 @@
 
 Handles: arbitrary-shape padding to block multiples, signed->bit-pattern
 conversion for the LUT kernel, padding-contribution correction (padded K rows
-contribute T[0,0] per row, subtracted after the call), and automatic
-interpret-mode fallback when not running on TPU (this container is CPU-only, so
-tests exercise the kernels with interpret=True; on TPU the same wrappers emit
-real Mosaic kernels).
+contribute T[0,0] per row, subtracted after the call), and the platform
+choice of how a kernel runs: compiled by Mosaic on a TPU, and in Pallas
+interpret mode everywhere else (the CPU test path). Nothing else selects
+interpret mode, so no kernel is ever interpreted on the chip.
 """
 from __future__ import annotations
 
@@ -21,7 +21,9 @@ from repro.core import error_delta, lut
 from . import approx_gemm, delta_gemm, systolic_gemm
 
 
-def _on_tpu() -> bool:
+def on_tpu() -> bool:
+    """True when a TPU backs JAX: kernels then lower through Mosaic; on any
+    other platform they run in interpret mode."""
     return jax.default_backend() == "tpu"
 
 
@@ -51,10 +53,9 @@ def _tpu_blocks(m: int, n: int, k: int, kernel: str, prefs, *, rank: int = 0):
 
 
 def systolic_matmul(a: jnp.ndarray, b: jnp.ndarray, *, bm: int | None = None,
-                    bn: int | None = None, bk: int | None = None,
-                    interpret: bool | None = None) -> jnp.ndarray:
+                    bn: int | None = None, bk: int | None = None) -> jnp.ndarray:
     """Exact int8 GEMM (int32 accumulate) for arbitrary (M, K) x (K, N)."""
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = not on_tpu()
     m, k = a.shape
     _, n = b.shape
     bm = bm or systolic_gemm.DEFAULT_BM
@@ -77,10 +78,17 @@ def systolic_matmul(a: jnp.ndarray, b: jnp.ndarray, *, bm: int | None = None,
 def approx_matmul(a: jnp.ndarray, b: jnp.ndarray, *, k: int = 4, n_bits: int = 8,
                   acc_bits: int = 24, signed: bool = True,
                   bm: int | None = None, bn: int | None = None,
-                  bk: int | None = None,
-                  interpret: bool | None = None) -> jnp.ndarray:
-    """Approximate GEMM at factor k for arbitrary shapes (signed operands)."""
-    interpret = (not _on_tpu()) if interpret is None else interpret
+                  bk: int | None = None) -> jnp.ndarray:
+    """Approximate GEMM at factor k for arbitrary shapes (signed operands).
+
+    The per-element product-table gather has no Mosaic lowering, so this
+    backend runs only off the chip; on a TPU ``approx_delta`` computes the
+    same bits on the MXU."""
+    if on_tpu():
+        raise NotImplementedError(
+            "approx_lut has no TPU lowering (Mosaic cannot gather from the "
+            "product table); use backend='approx_delta', which is "
+            "bit-identical to approx_lut at its default (exact) rank")
     span = 1 << n_bits
     mask = span - 1
     m, kd = a.shape
@@ -92,15 +100,11 @@ def approx_matmul(a: jnp.ndarray, b: jnp.ndarray, *, k: int = 4, n_bits: int = 8
     bm = bm or approx_gemm.DEFAULT_BM
     bn = bn or approx_gemm.DEFAULT_BN
     bk = bk or approx_gemm.DEFAULT_BK
-    if interpret:
-        bm_, bn_, bk_ = (_blocks(m, bm, 8), _blocks(n, bn, 8),
-                         _blocks(kd, bk, 8))
-    else:
-        bm_, bn_, bk_ = _tpu_blocks(m, n, kd, "lut", (bm, bn, bk))
+    bm_, bn_, bk_ = _blocks(m, bm, 8), _blocks(n, bn, 8), _blocks(kd, bk, 8)
     a_p = _pad_to(a_u, bm_, bk_)
     b_p = _pad_to(b_u, bk_, bn_)
     out = approx_gemm.approx_matmul_lut(a_p, b_p, table, span=span, bm=bm_,
-                                        bn=bn_, bk=bk_, interpret=interpret)
+                                        bn=bn_, bk=bk_, interpret=True)
     out = out[:m, :n]
     k_pad = a_p.shape[1] - kd
     if k_pad:
@@ -115,8 +119,7 @@ def approx_delta_matmul(a: jnp.ndarray, b: jnp.ndarray, *, k: int = 4,
                         rank: int | None = None, tol: float | None = None,
                         apply_residual: bool = True,
                         bm: int | None = None, bn: int | None = None,
-                        bk: int | None = None,
-                        interpret: bool | None = None) -> jnp.ndarray:
+                        bk: int | None = None) -> jnp.ndarray:
     """Approximate GEMM via the exact-plus-error-delta decomposition.
 
     Computes ``A_s @ B_s + round(F_A @ G_B)`` (see core/error_delta.py): one
@@ -127,7 +130,7 @@ def approx_delta_matmul(a: jnp.ndarray, b: jnp.ndarray, *, k: int = 4,
     ``apply_residual=True`` cancels again via a gather pass over the integer
     residual table.
     """
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = not on_tpu()
     fac = error_delta.delta_factors(n_bits, k, signed, acc_bits, rank=rank,
                                     tol=tol)
     span = 1 << n_bits
@@ -163,18 +166,24 @@ def approx_delta_matmul(a: jnp.ndarray, b: jnp.ndarray, *, k: int = 4,
         # commute with the defect cancellation, so run the fused kernel for the
         # base only and round correction + defect once (see error_delta docs)
         base = delta_gemm.delta_matmul_fused(
-            a_p, b_p, jnp.zeros((span,), jnp.float32),
-            jnp.zeros((span,), jnp.float32), rank=0, span=span, bm=bm_, bn=bn_,
-            bk=bk_, interpret=interpret)[:m, :n]
+            a_p, b_p, bm=bm_, bn=bn_, bk=bk_, interpret=interpret)[:m, :n]
         corr = (error_delta._correction(a_u, b_u, fac) if fac.rank
                 else jnp.zeros((m, n), jnp.float32))
         corr = corr + error_delta.defect_gather_matmul(a_u, b_u, fac)
         return base + jnp.round(corr).astype(jnp.int32)
-    f_flat, g_flat = error_delta.factor_tables_jnp(n_bits, k, signed, acc_bits,
-                                                   rank=rank, tol=tol)
-    out = delta_gemm.delta_matmul_fused(a_p, b_p, f_flat, g_flat, rank=fac.rank,
-                                        span=span, bm=bm_, bn=bn_, bk=bk_,
-                                        interpret=interpret)
+    f_a = g_b = None
+    if fac.rank:
+        f_flat, g_flat = error_delta.factor_tables_jnp(n_bits, k, signed,
+                                                       acc_bits, rank=rank,
+                                                       tol=tol)
+        # per-element factor lookups, gathered here: the kernel itself holds
+        # MXU contractions only (padded zeros look up pattern 0)
+        f_a = jnp.moveaxis(jnp.take(f_flat.reshape(span, fac.rank),
+                                    a_p & mask, axis=0), -1, 0)  # (r, M, K)
+        g_b = jnp.take(g_flat.reshape(fac.rank, span), b_p & mask,
+                       axis=1)                                   # (r, K, N)
+    out = delta_gemm.delta_matmul_fused(a_p, b_p, f_a, g_b, bm=bm_, bn=bn_,
+                                        bk=bk_, interpret=interpret)
     out = out[:m, :n]
     k_pad = a_p.shape[1] - kd
     if k_pad and fac.rank:

@@ -1,12 +1,15 @@
-"""Production meshes. TPU v5e numbers: 256 chips/pod (16x16), 2 pods = 512.
+"""Device meshes, all with Auto axes.
 
-`make_production_mesh` is a function (not a module constant) so importing this
-module never touches jax device state; `dryrun.py` sets
+`local_mesh` / `make_debug_mesh` span the devices present (the train
+driver's mesh). `make_production_mesh` builds the 256/512-chip v5e pod
+meshes for the dry-run lowering only; `dryrun.py` sets
 XLA_FLAGS=--xla_force_host_platform_device_count=512 before first jax init.
+These are functions, so importing this module never touches device state.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 SINGLE_POD = (16, 16)
 MULTI_POD = (2, 16, 16)
@@ -32,10 +35,22 @@ def make_production_mesh(*, multi_pod: bool = False,
         raise RuntimeError(
             f"need {n} devices (set XLA_FLAGS=--xla_force_host_platform_device_count=512), "
             f"have {len(devices)}")
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return auto_mesh(shape, axes, devices[:n])
 
 
 def make_debug_mesh(n_data: int = 1, n_model: int = 1) -> jax.sharding.Mesh:
-    """Tiny mesh over available devices for CPU tests."""
-    return jax.make_mesh((n_data, n_model), ("data", "model"),
-                         devices=jax.devices()[: n_data * n_model])
+    """(data, model) mesh over the first ``n_data * n_model`` devices."""
+    return auto_mesh((n_data, n_model), ("data", "model"),
+                      jax.devices()[: n_data * n_model])
+
+
+def local_mesh() -> jax.sharding.Mesh:
+    """(data, model) mesh with every device present on the data axis."""
+    return make_debug_mesh(len(jax.devices()), 1)
+
+
+def auto_mesh(shape, axes, devices) -> jax.sharding.Mesh:
+    """Mesh with Auto axes: the model code places activations with
+    `with_sharding_constraint`, which only Auto axes accept."""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))

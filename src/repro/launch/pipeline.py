@@ -21,7 +21,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def split_stages(stacked, n_stages: int):
@@ -77,6 +76,7 @@ def make_pipelined_apply(stage_fn: Callable, n_stages: int, mesh: Mesh,
 
     def apply(staged_params, x_micro):
         in_specs = (jax.tree.map(lambda _: P(axis), staged_params), P())
-        return shard_map(pipelined, mesh=mesh, in_specs=in_specs,
-                         out_specs=P(), check_rep=False)(staged_params, x_micro)
+        return jax.shard_map(pipelined, mesh=mesh, in_specs=in_specs,
+                             out_specs=P(), check_vma=False)(staged_params,
+                                                             x_micro)
     return apply

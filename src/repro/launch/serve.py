@@ -37,6 +37,7 @@ from repro.configs import ARCHS, reduced
 from repro.core import gemm
 from repro.models import get_model
 
+from . import compile_cache
 from . import engine as engine_mod
 from . import sampling
 
@@ -61,9 +62,12 @@ def _lockstep_steps(cfg, policy):
 
 
 def lockstep_generate(cfg, model, params, prompts, gen_len, *,
-                      policy=gemm.EXACT, input_embeds=None):
+                      policy=gemm.EXACT, input_embeds=None,
+                      return_logits=False):
     """The lockstep reference: batched prefill + greedy decode, one scalar
-    position shared by the whole batch. Returns (B, gen_len) int32 tokens.
+    position shared by the whole batch. Returns (B, gen_len) int32 tokens,
+    and with ``return_logits`` also the (B, gen_len, V) float32 logits each
+    token was picked from.
 
     Per-request bit-parity contract: running a request alone here (batch 1)
     produces exactly the tokens the continuous-batching engine produces for
@@ -81,11 +85,17 @@ def lockstep_generate(cfg, model, params, prompts, gen_len, *,
     logits, cache = prefill_j(params, batch, cache)
     tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
     out_tokens = [np.asarray(tok)]
+    out_logits = [np.asarray(logits[:, -1:], np.float32)] if return_logits else []
     for i in range(gen_len - 1):
         logits, cache = decode_j(params, tok, cache, jnp.int32(start + i))
         tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
         out_tokens.append(np.asarray(tok))
-    return np.concatenate(out_tokens, axis=1)
+        if return_logits:
+            out_logits.append(np.asarray(logits[:, -1:], np.float32))
+    tokens = np.concatenate(out_tokens, axis=1)
+    if return_logits:
+        return tokens, np.concatenate(out_logits, axis=1)
+    return tokens
 
 
 def load_trace(path, vocab_size, default_prompt_len, default_gen_len, *,
@@ -296,4 +306,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -1,9 +1,9 @@
 """Training driver: --arch <id> [--shape train_4k] with the full production
 stack (mesh, shardings, microbatching, AdamW, checkpointing, fault tolerance).
 
-On CPU (this container) use --debug to train a reduced config on a 1x1 mesh —
-that is the end-to-end example path. On a real TPU slice the same driver runs
-the full config on the production mesh.
+On CPU use --debug to train a reduced config on a 1x1 mesh — that is the
+end-to-end example path. On a TPU host the same driver runs the full config
+data-parallel over every chip present.
 
 Run:  PYTHONPATH=src python -m repro.launch.train --arch smollm-360m --debug \
           --steps 30 --ckpt-dir /tmp/ckpt
@@ -17,7 +17,7 @@ import jax
 
 from repro.configs import ARCHS, reduced
 from repro.configs.base import ShapeSpec
-from repro.launch import mesh as mesh_mod
+from repro.launch import compile_cache, mesh as mesh_mod
 from repro.launch.steps import TrainHParams, assemble_train, default_micro
 from repro.models import get_model
 from repro.train.loop import LoopConfig, train
@@ -37,7 +37,6 @@ def main(argv=None):
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=50)
-    ap.add_argument("--multi-pod", action="store_true")
     args = ap.parse_args(argv)
 
     cfg = ARCHS[args.arch]
@@ -47,7 +46,7 @@ def main(argv=None):
         mesh = mesh_mod.make_debug_mesh(1, 1)
     else:
         shape = cfg.shape(args.shape)
-        mesh = mesh_mod.make_production_mesh(multi_pod=args.multi_pod)
+        mesh = mesh_mod.local_mesh()
     n_micro = args.n_micro or (1 if args.debug else default_micro(cfg, shape))
     hp = TrainHParams(n_micro=n_micro, peak_lr=args.lr,
                       total_steps=args.steps)
@@ -66,4 +65,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -276,9 +276,11 @@ def test_gemm_plan_never_rejected(m, n, k, kernel, rank, budget_mib):
 def test_gemm_plan_shrinks_under_tight_budget():
     full = kernel_audit.gemm_block_plan(4096, 4096, 4096, kernel="delta",
                                         rank=21)
+    # the rank-21 correction factors stream as (21, bm, bk) / (21, bk, bn)
+    # float32 blocks beside A and B, so they dominate the cell's VMEM
     tight = kernel_audit.gemm_block_plan(4096, 4096, 4096, kernel="delta",
-                                         rank=21, vmem_budget=MiB // 2)
-    assert full == (256, 256, 256)
+                                         rank=21, vmem_budget=8 * MiB)
+    assert full == (256, 256, 128)
     # a tighter budget shrinks the plan (some block halved) but never below
     # the MXU tile edge — and the result is still contract-clean
     import math

@@ -154,7 +154,7 @@ def test_bdcn_hybrid_policy_overrides_match_legacy_split():
         got = bdcn.bdcn_forward(img, ws, 6, n_approx_blocks=n_approx)
         np.testing.assert_array_equal(got, want)
     pol = bdcn.hybrid_policy(6, n_approx_blocks=1, n_blocks=4)
-    assert pol.resolve(bdcn.layer_name(0)) == "approx_lut"
+    assert pol.resolve(bdcn.layer_name(0)) == "approx_delta"
     assert pol.resolve(bdcn.layer_name(3)) == "exact"
 
 

@@ -28,6 +28,7 @@ _SUBPROC = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
+    from repro.launch.mesh import auto_mesh
     from repro.launch.pipeline import make_pipelined_apply, split_stages
 
     L, D, M, B = 4, 8, 3, 2
@@ -54,8 +55,7 @@ _SUBPROC = textwrap.dedent("""
     want = jax.vmap(seq)(x)
 
     for n_stages in (2, 4):
-        mesh = jax.make_mesh((n_stages,), ("pod",),
-                             devices=jax.devices()[:n_stages])
+        mesh = auto_mesh((n_stages,), ("pod",), jax.devices()[:n_stages])
         staged = split_stages(stacked, n_stages)
         apply_fn = make_pipelined_apply(stage_fn, n_stages, mesh)
         with mesh:
