@@ -79,6 +79,7 @@ from repro.models import api as model_api
 from repro.train.fault import TransientError
 from . import paged as paged_mod
 from . import sampling
+from . import spans
 from . import steps as steps_mod
 
 PyTree = Any
@@ -276,6 +277,7 @@ class Request:
     ttft_deadline: Optional[int] = None
     total_deadline: Optional[int] = None
     preempt_count: int = 0                  # engine-maintained (aging)
+    queued_ns: int = 0                      # engine-maintained: queued (ns)
 
 
 @dataclasses.dataclass
@@ -367,6 +369,11 @@ class ServeEngine:
         self.eos_id = eos_id
         self.paged = paged
         self.paged_kernel = paged_kernel
+        # cumulative counters: each step phase's self time (``<phase>_ns``,
+        # `spans.Phases`) and the queue's admissions with their summed wait
+        # from submit; the paged engine adds its occupancy counters
+        self.occ: Dict[str, int] = {"queue_wait_ns": 0, "admitted": 0}
+        self._phase = spans.Phases(self.occ)
 
         if paged:
             spec = (paged_mod.PagedSpec(n_blocks, block_size)
@@ -382,9 +389,9 @@ class ServeEngine:
             self.slot_pos = np.zeros(max_slots, np.int64)
             self._tables_dev = None          # device mirror, rebuilt on change
             self._dev_cache = {}             # step-input mirrors, see _dev_cached
-            self.occ = {"slot_steps": 0, "slot_active_steps": 0,
-                        "block_steps": 0, "block_alloc_steps": 0,
-                        "prefill_tokens": 0, "decode_tokens": 0}
+            self.occ.update({"slot_steps": 0, "slot_active_steps": 0,
+                             "block_steps": 0, "block_alloc_steps": 0,
+                             "prefill_tokens": 0, "decode_tokens": 0})
             # prefix caching is sound only when every cache leaf lives in
             # the shared pool: families with per-slot state outside it (ring
             # buffers, SSM/xLSTM recurrent state) can't resume mid-prompt
@@ -436,7 +443,6 @@ class ServeEngine:
         self.decode_steps = 0
         self.peak_active = 0                 # measured, both engine modes
         self.host_syncs = 0                  # token-block device->host syncs
-        self.backoff_s_total = 0.0           # measured retry wait (stats)
         # reliability counters, surfaced through `stats` and serve.py
         self.events = {REJECTED_QUEUE_FULL: 0, "cancelled": 0,
                        "deadline_ttft": 0, "deadline_total": 0,
@@ -476,6 +482,7 @@ class ServeEngine:
                 and len(self.queue) >= self.queue_limit):
             self._finish_unstarted(request, REJECTED_QUEUE_FULL)
             return False
+        request.queued_ns = time.perf_counter_ns()
         self.queue.append(request)
         return True
 
@@ -577,8 +584,9 @@ class ServeEngine:
         self.slot_pos[slot] = resume
         self.slot_chain[slot] = keys
         self.slot_cacheable[slot] = self._cacheable(req)
+        self._count_admission(req)
         if self._guard:                      # admit wiped the slot's cache
-            self._cache_fp = abft.tree_fingerprint(self._scrub_view())
+            self._refresh_cache_fp()
 
     def _admit(self, slot: int, req: Request) -> None:
         start = self._start_len(req)
@@ -600,9 +608,14 @@ class ServeEngine:
         self.slot_out[slot] = [int(first)]
         self.host_syncs += 1                 # the fused admit syncs `first`
         self.slot_admitted[slot] = self.step_count
+        self._count_admission(req)
         if self._guard:                      # admit wrote the slot's cache
-            self._cache_fp = abft.tree_fingerprint(self._scrub_view())
+            self._refresh_cache_fp()
         self._maybe_retire(slot)
+
+    def _count_admission(self, req: Request) -> None:
+        self.occ["queue_wait_ns"] += time.perf_counter_ns() - req.queued_ns
+        self.occ["admitted"] += 1
 
     def _budget(self, req: Request) -> int:
         # token n's producing decode writes its KV at cache offset
@@ -674,6 +687,7 @@ class ServeEngine:
         req.preempt_count += 1               # aging: no starvation
         self._free_slot(slot)
         self.events["preemptions"] += 1
+        req.queued_ns = time.perf_counter_ns()   # its wait starts again
         return req
 
     def _eff_priority(self, req: Request) -> int:
@@ -804,8 +818,35 @@ class ServeEngine:
 
     def _paged_step(self) -> None:
         """One mixed prefill+decode chunk step over all slots."""
-        live = np.flatnonzero(self.active)
-        prefilling = [s for s in live if self.slot_prefill_off[s] is not None]
+        with self._phase("prepare"):
+            live = np.flatnonzero(self.active)
+            prefilling = [s for s in live
+                          if self.slot_prefill_off[s] is not None]
+            args, clens, emit = self._chunk_inputs(live, prefilling)
+        # dispatch with recovery: params are read at call time (a retry after
+        # restore-from-pristine must not replay the poisoned leaves) and
+        # nothing below mutates scheduler state, so a retried or quarantined
+        # step cannot double-commit (`_chunk_inputs`' pool.ensure is
+        # idempotent)
+        with self._phase("dispatch"):
+            dispatched = self._dispatch(
+                lambda: self._chunk(self.params, *args))
+            if dispatched is None:           # quarantined: step consumed
+                self.step_count += 1
+                return
+            # the step's inputs, the previous cache and state among them,
+            # are freed here, inside the phase
+            del args
+            tok, self.cache, self.state = dispatched
+        with self._phase("sync"):
+            tok_np = np.asarray(tok)         # the one per-step device sync
+        with self._phase("commit"):
+            self._commit_chunk(live, prefilling, clens, emit, tok_np)
+
+    def _chunk_inputs(self, live, prefilling):
+        """The chunk step's arguments after the params, the prompt chunk
+        length of each prefilling slot, and the emit mask; allocates the
+        step's blocks and applies pending copy-on-write clones first."""
         # step width: the widest remaining chunk this step actually needs
         # (bounded by prefill_chunk, so at most prefill_chunk distinct
         # compiled widths) — decode rows in a mixed step pay for the width,
@@ -872,22 +913,18 @@ class ServeEngine:
                 self._dev_cached("emit", emit)]
         if vlm:
             args += [jnp.asarray(embeds), jnp.asarray(emask)]
-        # dispatch with recovery: params are read at call time (a retry after
-        # restore-from-pristine must not replay the poisoned leaves) and
-        # nothing below mutates scheduler state, so a retried or quarantined
-        # step cannot double-commit (pool.ensure above is idempotent)
-        dispatched = self._dispatch(lambda: self._chunk(self.params, *args))
-        if dispatched is None:               # quarantined: step consumed
-            self.step_count += 1
-            return
-        tok, self.cache, self.state = dispatched
-        tok_np = np.asarray(tok)             # the one per-step device sync
+        return args, clens, emit
+
+    def _commit_chunk(self, live, prefilling, clens, emit, tok_np) -> None:
+        """Host bookkeeping after a chunk step: counters, outputs, prefix
+        publication and retirement."""
         self.host_syncs += 1
         if self._guard:
-            self._cache_fp = abft.tree_fingerprint(self._scrub_view())
+            self._refresh_cache_fp()
         self.step_count += 1
         if len(prefilling) < len(live):
             self.decode_steps += 1
+        b = self.n_slots
         self.occ["slot_steps"] += b
         self.occ["slot_active_steps"] += len(live)
         self.occ["block_steps"] += self.pool.spec.n_blocks
@@ -926,7 +963,7 @@ class ServeEngine:
         dst = jnp.asarray([c[1] for c in copies], jnp.int32)
         self.cache = self._copy_blocks(self.cache, src, dst)
         if self._guard:
-            self._cache_fp = abft.tree_fingerprint(self._scrub_view())
+            self._refresh_cache_fp()
 
     def _multi_horizon(self) -> None:
         """One fused ``multi_step``-sub-step decode horizon (single dispatch).
@@ -946,31 +983,43 @@ class ServeEngine:
         n = self.multi_step
         live = np.flatnonzero(self.active)
         if self.paged:
-            # horizon-aware alloc-on-write: cover the worst case (all n
-            # sub-steps live) up front; ensure_horizon clamps to the
-            # admit-time reservation, which the device-side budget mask
-            # provably never writes past
-            tables_dirty = self._tables_dev is None
-            for s in live:
-                tables_dirty |= self.pool.ensure_horizon(
-                    s, int(self.slot_pos[s]) + n)
-            self._apply_cow()
-            if tables_dirty:
-                self._tables_dev = jnp.asarray(self.pool.tables)
-            self.cache = dict(self.cache, block_tables=self._tables_dev)
+            with self._phase("prepare"):
+                self._horizon_blocks(live, n)
         # recovery composes unchanged: cache/state are only assigned on
         # success, so a retry replays the whole horizon from the pre-horizon
         # snapshot and the replay is bit-identical
-        dispatched = self._dispatch(
-            lambda: self._multi(self.params, self.cache, self.state))
-        if dispatched is None:               # quarantined: horizon consumed
-            self.step_count += n
-            return
-        toks, self.cache, self.state = dispatched
-        tok_np = np.asarray(toks)            # the one per-*horizon* sync
+        with self._phase("dispatch"):
+            dispatched = self._dispatch(
+                lambda: self._multi(self.params, self.cache, self.state))
+            if dispatched is None:           # quarantined: horizon consumed
+                self.step_count += n
+                return
+            toks, self.cache, self.state = dispatched
+        with self._phase("sync"):
+            tok_np = np.asarray(toks)        # the one per-*horizon* sync
+        with self._phase("commit"):
+            self._commit_horizon(live, n, tok_np)
+
+    def _horizon_blocks(self, live, n: int) -> None:
+        """Horizon-aware alloc-on-write: cover the worst case (all ``n``
+        sub-steps live) up front; ensure_horizon clamps to the admit-time
+        reservation, which the device-side budget mask provably never writes
+        past."""
+        tables_dirty = self._tables_dev is None
+        for s in live:
+            tables_dirty |= self.pool.ensure_horizon(
+                s, int(self.slot_pos[s]) + n)
+        self._apply_cow()
+        if tables_dirty:
+            self._tables_dev = jnp.asarray(self.pool.tables)
+        self.cache = dict(self.cache, block_tables=self._tables_dev)
+
+    def _commit_horizon(self, live, n: int, tok_np) -> None:
+        """Host bookkeeping after a horizon, replayed per sub-step from the
+        ``(n, B)`` token block."""
         self.host_syncs += 1
         if self._guard:
-            self._cache_fp = abft.tree_fingerprint(self._scrub_view())
+            self._refresh_cache_fp()
         self.step_count += n
         b = self.n_slots
         for j in range(n):
@@ -1058,7 +1107,12 @@ class ServeEngine:
         self.cache = self.model.init_paged_cache(
             self.n_slots, self.max_len, spec.n_blocks, spec.block_size)
         self._tables_dev = None
-        self._cache_fp = abft.tree_fingerprint(self._scrub_view())
+        self._refresh_cache_fp()
+
+    def _refresh_cache_fp(self) -> None:
+        """Fingerprint the KV cache again after a legitimate rewrite."""
+        with self._phase("scrub"):
+            self._cache_fp = abft.tree_fingerprint(self._scrub_view())
 
     def _backoff_wait(self, attempts: int) -> None:
         """Retry backoff as a monotonic-deadline wait.
@@ -1068,20 +1122,20 @@ class ServeEngine:
         scheduler far past the step budget. The wait is now capped by
         ``retry_backoff_cap_s``, sleeps in short slices against a
         ``time.monotonic`` deadline (immune to wall-clock jumps), and the
-        time actually spent is surfaced in ``stats["backoff_s_total"]``.
+        time actually spent is the ``backoff`` phase (``occ["backoff_ns"]``,
+        ``stats["backoff_s_total"]``).
         """
         if not self.retry_backoff_s:
             return
         want = self.retry_backoff_s * attempts
         if self.retry_backoff_cap_s is not None:
             want = min(want, self.retry_backoff_cap_s)
-        t0 = time.monotonic()
-        deadline = t0 + want
-        remaining = want
-        while remaining > 0:
-            time.sleep(min(remaining, 0.02))
-            remaining = deadline - time.monotonic()
-        self.backoff_s_total += time.monotonic() - t0
+        with self._phase("backoff"):
+            deadline = time.monotonic() + want
+            remaining = want
+            while remaining > 0:
+                time.sleep(min(remaining, 0.02))
+                remaining = deadline - time.monotonic()
 
     def _dispatch(self, step_fn):
         """Run one jitted step under the recovery protocol.
@@ -1101,10 +1155,12 @@ class ServeEngine:
         while True:
             try:
                 if self._guard:
-                    self._scrub()
+                    with self._phase("scrub"):
+                        self._scrub()
                 out = step_fn()
                 if self._guard:
-                    jax.block_until_ready(out)
+                    with self._phase("sync"):
+                        jax.block_until_ready(out)
                     faults = abft.drain_faults()
                     if faults:
                         raise abft.AbftFaultError(faults)
@@ -1136,20 +1192,24 @@ class ServeEngine:
         # and refreshes the fingerprint, so corruption struck between steps
         # must be caught before any admit can launder it into the baseline
         if self._guard and self._cache_fp is not None:
-            bad = abft.verify_fingerprint(self._scrub_view(), self._cache_fp)
-            if bad:
-                self.events["faults_detected"] += len(bad)
-                if not self.paged:           # contiguous: fail fast
-                    raise abft.AbftFaultError(
-                        [abft.Fault(f"cache:{p}", "memory", 1.0, 0.0)
-                         for p in bad])
-                self._quarantine()
-                self.step_count += 1         # step consumed by recovery
-                return
-        self._enforce_deadlines()
-        self._admit_ready()
-        self.peak_active = max(self.peak_active, int(self.active.sum()))
-        if not self.active.any():
+            with self._phase("scrub"):
+                bad = abft.verify_fingerprint(self._scrub_view(),
+                                              self._cache_fp)
+                if bad:
+                    self.events["faults_detected"] += len(bad)
+                    if not self.paged:       # contiguous: fail fast
+                        raise abft.AbftFaultError(
+                            [abft.Fault(f"cache:{p}", "memory", 1.0, 0.0)
+                             for p in bad])
+                    self._quarantine()
+                    self.step_count += 1     # step consumed by recovery
+                    return
+        with self._phase("admit"):
+            self._enforce_deadlines()
+            self._admit_ready()
+            self.peak_active = max(self.peak_active, int(self.active.sum()))
+            idle = not self.active.any()
+        if idle:
             self.step_count += 1             # idle tick (waiting on arrivals)
             return
         if self.paged:
@@ -1167,18 +1227,20 @@ class ServeEngine:
         if self.multi_step > 1:
             self._multi_horizon()
             return
-        next_tok, cache, state = self._dispatch(
-            lambda: self._decode(self.params, self.cache, self.state))
-        self.cache, self.state = cache, state
-        next_np = np.asarray(next_tok)       # the one per-step device sync
-        self.host_syncs += 1
-        if self._guard:
-            self._cache_fp = abft.tree_fingerprint(self._scrub_view())
-        self.step_count += 1
-        self.decode_steps += 1
-        for slot in np.flatnonzero(self.active):
-            self.slot_out[slot].append(int(next_np[slot]))
-            self._maybe_retire(slot)
+        with self._phase("dispatch"):
+            next_tok, self.cache, self.state = self._dispatch(
+                lambda: self._decode(self.params, self.cache, self.state))
+        with self._phase("sync"):
+            next_np = np.asarray(next_tok)   # the one per-step device sync
+        with self._phase("commit"):
+            self.host_syncs += 1
+            if self._guard:
+                self._refresh_cache_fp()
+            self.step_count += 1
+            self.decode_steps += 1
+            for slot in np.flatnonzero(self.active):
+                self.slot_out[slot].append(int(next_np[slot]))
+                self._maybe_retire(slot)
 
     def run(self, requests: Sequence[Request] = (),
             max_steps: Optional[int] = None) -> Dict[int, FinishedRequest]:
@@ -1193,6 +1255,7 @@ class ServeEngine:
     @property
     def stats(self) -> Dict[str, Any]:
         gen = sum(len(f.tokens) for f in self.finished.values())
+        occ = self.occ
         out: Dict[str, Any] = {
             "steps": self.step_count, "decode_steps": self.decode_steps,
             "generated_tokens": gen, "finished": len(self.finished),
@@ -1202,10 +1265,15 @@ class ServeEngine:
             "multi_step": self.multi_step,
             "host_syncs": self.host_syncs,
             "syncs_per_token": round(self.host_syncs / max(1, gen), 4),
-            "backoff_s_total": round(self.backoff_s_total, 6)}
+            "backoff_s_total": round(occ["backoff_ns"] * 1e-9, 6)}
+        # time in each step phase (`spans`) and the mean wait in the queue
+        # from submit to admission
+        out.update({f"{p}_ms": round(occ[spans.counter(p)] * 1e-6, 3)
+                    for p in spans.PHASES})
+        out["queue_wait_mean_ms"] = round(
+            occ["queue_wait_ns"] * 1e-6 / max(1, occ["admitted"]), 3)
         out.update(self.events)              # reliability counters
         if self.paged:
-            occ = self.occ
             out.update({
                 # occupancy: fraction of slot-steps / pool-block-steps that
                 # held live work, plus the prefill-vs-decode token split

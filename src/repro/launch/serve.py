@@ -260,6 +260,11 @@ def main(argv=None):
         print(f"host syncs: {st['host_syncs']} "
               f"({st['syncs_per_token']:.3f}/token, "
               f"multi_step={st['multi_step']})")
+        per_step = 1.0 / max(1, st["steps"])
+        print("step phases: " + " ".join(
+            f"{p} {st[p + '_ms'] * per_step:.3f}"
+            for p in ("admit", "prepare", "dispatch", "sync", "commit"))
+            + f" ms/step; queue wait {st['queue_wait_mean_ms']:.3f} ms mean")
         if args.paged:
             tok_total = max(1, st["prefill_tokens"] + st["decode_tokens"])
             print(f"occupancy: slots {st['slot_utilization']:.1%} "
