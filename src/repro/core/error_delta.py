@@ -23,7 +23,11 @@ approximate GEMM becomes **two MXU matmuls** instead of O(M·N·K) VPU gathers:
 
 with ``F_A[m, kk*r + j] = f[a_u[m, kk], j]`` and
 ``G_B[kk*r + j, n] = g[j, b_u[kk, n]]`` — per-element lookups into 256-entry
-vectors, trivially VMEM-resident.  Rounding the correction **per K-block** (as
+vectors.  Since ``E`` tiles every 2^k patterns, so do ``f`` and ``g``, and the
+lookup needs only the low k bits of an operand: up to ``SELECT_MAX_PATTERNS`` patterns it is a chain of 2^k
+elementwise selects on ``x_u & (2^k - 1)`` (plain VPU work that XLA fuses, no
+gather), above that a gather over the full table (``moving_factor``).
+Rounding the correction **per K-block** (as
 the fused Pallas kernel in ``kernels/delta_gemm.py`` does) keeps the result
 bit-identical to the gather path for any K, because each block's true
 correction is an integer and the float32 noise per block is ~1e-2 << 0.5.
@@ -39,6 +43,7 @@ truncation exactly).
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 from typing import NamedTuple, Optional, Tuple
@@ -196,17 +201,52 @@ def _device_factors(n_bits: int, k: int, signed: bool, acc_bits: int,
                 jnp.asarray(fac.defect.reshape(-1)))
 
 
+# Up to this many low-bit patterns the moving operand's factor is picked by
+# elementwise selects; above it, by a gather over the whole table. Set from a
+# TPU v5e microbenchmark of one bound GEMM at M=512, K and N of 960 and 2560
+# (benchmarks/delta_lookup.py): the selects win up to k=6 (64 patterns), the
+# gather at k=7 and 8.
+SELECT_MAX_PATTERNS = 64
+
+
+def select_bits(n_bits: int, k: int) -> Optional[int]:
+    """Low bits the select lookup reads (the table repeats every 2^bits
+    columns), or None where there are too many patterns and it gathers."""
+    bits = min(k, n_bits)
+    return bits if (1 << bits) <= SELECT_MAX_PATTERNS else None
+
+
+def moving_factor(x_u: jnp.ndarray, tab: jnp.ndarray,
+                  bits: Optional[int]) -> jnp.ndarray:
+    """``tab[:, x_u]`` as float32 ``(r, *x_u.shape)``: the moving operand's
+    correction factor, looked up by its bit patterns in ``tab (r, span)``.
+
+    With ``bits`` set (``select_bits``) only the first 2^bits columns are
+    read, by a chain of selects on ``x_u``'s low bits (pattern 0 is the
+    default and needs no compare); the result comes out in the layout the
+    contraction takes, so no gather and no relayout remain. With ``bits``
+    None it gathers over the whole table."""
+    r = tab.shape[0]
+    if bits is None:
+        return jnp.take(tab, x_u.reshape(-1), axis=1).reshape((r,) + x_u.shape)
+    low = (x_u & ((1 << bits) - 1))[None]
+    cols = tab[:, :1 << bits].reshape((r, 1 << bits) + (1,) * x_u.ndim)
+    out = jnp.broadcast_to(cols[:, 0], (r,) + x_u.shape)
+    for p in range(1, 1 << bits):
+        out = jnp.where(low == p, cols[:, p], out)
+    return out
+
+
 def _correction(a_u: jnp.ndarray, b_u: jnp.ndarray, fac: DeltaFactors) -> jnp.ndarray:
-    """Unrounded rank-r correction: (M, rK) x (rK, N) float32 matmul."""
-    m, kd = a_u.shape
-    n = b_u.shape[1]
+    """Unrounded rank-r correction: (r, M, K) x (r, K, N) float32 contraction
+    over the rank and K."""
     f_dev, g_dev, _ = _device_factors(fac.n_bits, fac.k, fac.signed,
                                       fac.acc_bits, fac.rank)
-    f_a = jnp.take(f_dev, a_u, axis=0)                        # (M, K, r)
-    g_b = jnp.take(g_dev, b_u, axis=1)                        # (r, K, N)
-    return jnp.matmul(f_a.reshape(m, kd * fac.rank),
-                      jnp.transpose(g_b, (1, 0, 2)).reshape(kd * fac.rank, n),
-                      precision=jax.lax.Precision.HIGHEST)
+    bits = select_bits(fac.n_bits, fac.k)
+    f_a = moving_factor(a_u, f_dev.T, bits)                   # (r, M, K)
+    g_b = moving_factor(b_u, g_dev, bits)                     # (r, K, N)
+    return jax.lax.dot_general(f_a, g_b, (((0, 2), (0, 1)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST)
 
 
 def defect_gather_matmul(a_u: jnp.ndarray, b_u: jnp.ndarray,
@@ -263,6 +303,12 @@ class PreparedDelta:
         n_bits, k, signed, acc_bits, rank_req, tol_req = self.spec
         return delta_factors(n_bits, k, signed, acc_bits, rank=rank_req,
                              tol=tol_req)
+
+    @property
+    def form(self) -> str:
+        """How the moving operand's factor is looked up: ``"select"`` on its
+        low k bits, or ``"gather"`` over the whole table."""
+        return "gather" if select_bits(*self.spec[:2]) is None else "select"
 
 
 jax.tree_util.register_pytree_node(
@@ -426,10 +472,10 @@ def prepare_delta(w, *, side: str = "right", n_bits: int = 8, k: int = 4,
 
 
 @functools.partial(jax.jit, static_argnames=("side", "rank", "n_bits",
-                                             "signed", "use_defect"))
+                                             "signed", "use_defect", "bits"))
 def _delta_prepared_impl(x, w_u, w_s, factor, gather_tab, defect_flat, *,
                          side: str, rank: int, n_bits: int, signed: bool,
-                         use_defect: bool):
+                         use_defect: bool, bits: Optional[int]):
     span = 1 << n_bits
     x_u = jnp.asarray(x, jnp.int32) & (span - 1)
     x_s = _signed_values(x_u, n_bits, signed)
@@ -440,19 +486,16 @@ def _delta_prepared_impl(x, w_u, w_s, factor, gather_tab, defect_flat, *,
         a_u, b_u = w_u, x_u
         base = _base_matmul(w_s, x_s, signed)
     if rank:
-        # transposed gather — r' row-contiguous sweeps over the flat moving
-        # indices (far faster than per-index rank-r row gathers on CPU), then
-        # one two-axis contraction against the precomputed stationary factor
-        mov = jnp.take(gather_tab, x_u.reshape(-1), axis=1)
+        # the moving factor in the (r, ...) layout, then one two-axis
+        # contraction against the precomputed stationary factor
+        mov = moving_factor(x_u, gather_tab, bits)
         if side == "right":
-            m, kd = x_u.shape
             corr = jax.lax.dot_general(                 # (r,M,K) x (K,r,N)
-                mov.reshape(rank, m, kd), factor, (((0, 2), (1, 0)), ((), ())),
+                mov, factor, (((0, 2), (1, 0)), ((), ())),
                 precision=jax.lax.Precision.HIGHEST)
         else:
-            kd, n = x_u.shape
             corr = jax.lax.dot_general(                 # (M,K,r) x (r,K,N)
-                factor, mov.reshape(rank, kd, n), (((1, 2), (1, 0)), ((), ())),
+                factor, mov, (((1, 2), (1, 0)), ((), ())),
                 precision=jax.lax.Precision.HIGHEST)
     else:
         corr = jnp.zeros(base.shape, jnp.float32)
@@ -462,19 +505,27 @@ def _delta_prepared_impl(x, w_u, w_s, factor, gather_tab, defect_flat, *,
     return base + jnp.round(corr).astype(jnp.int32)
 
 
+# Call sites of `delta_matmul_prepared` per lookup form (`PreparedDelta.form`),
+# counted whenever Python runs the call: once per trace under jit, once per
+# call when eager. Process-wide; `ServeEngine.stats` reports it.
+FORM_SITES: collections.Counter = collections.Counter()
+
+
 def delta_matmul_prepared(x, prep: PreparedDelta, *,
                           apply_residual: bool = True) -> jnp.ndarray:
-    """Weight-stationary delta GEMM: only the moving operand ``x`` is gathered.
+    """Weight-stationary delta GEMM: only the moving operand ``x`` is looked up.
 
     ``x`` is the activations — (M, K) when the prepared weights are on the
     right, (K, N) when on the left. The whole call is one jit'd fusion of the
-    exact int8 base matmul, the moving operand's rank-r' gathers, and the
-    correction contraction against the precomputed stationary factor.
+    exact int8 base matmul, the moving operand's rank-r' factor lookup
+    (``moving_factor``), and the correction contraction against the
+    precomputed stationary factor.
     Bit-identical to ``delta_matmul_ref`` / ``lut.lut_matmul`` at the exact
     rank and at any rank with ``apply_residual=True`` (single global rounding
     over correction + defect, exact while K·max|E|·eps_f32 stays far below
     0.5 — all app workloads)."""
     fac = prep.fac
+    FORM_SITES[prep.form] += 1
     use_defect = apply_residual and not fac.exact
     if use_defect:
         _, _, defect_flat = _device_factors(fac.n_bits, fac.k, fac.signed,
@@ -484,7 +535,8 @@ def delta_matmul_prepared(x, prep: PreparedDelta, *,
     return _delta_prepared_impl(x, prep.w_u, prep.w_s, prep.factor,
                                 prep.gather_tab, defect_flat, side=prep.side,
                                 rank=prep.rank, n_bits=fac.n_bits,
-                                signed=fac.signed, use_defect=use_defect)
+                                signed=fac.signed, use_defect=use_defect,
+                                bits=select_bits(fac.n_bits, fac.k))
 
 
 def delta_matmul_ref(a, b, *, k: int = 4, n_bits: int = 8, signed: bool = True,

@@ -176,12 +176,13 @@ def approx_delta_matmul(a: jnp.ndarray, b: jnp.ndarray, *, k: int = 4,
         f_flat, g_flat = error_delta.factor_tables_jnp(n_bits, k, signed,
                                                        acc_bits, rank=rank,
                                                        tol=tol)
-        # per-element factor lookups, gathered here: the kernel itself holds
-        # MXU contractions only (padded zeros look up pattern 0)
-        f_a = jnp.moveaxis(jnp.take(f_flat.reshape(span, fac.rank),
-                                    a_p & mask, axis=0), -1, 0)  # (r, M, K)
-        g_b = jnp.take(g_flat.reshape(fac.rank, span), b_p & mask,
-                       axis=1)                                   # (r, K, N)
+        # the per-element factor lookups happen here: the kernel itself
+        # holds MXU contractions only (padded zeros look up pattern 0)
+        bits = error_delta.select_bits(n_bits, k)
+        f_a = error_delta.moving_factor(
+            a_p & mask, f_flat.reshape(span, fac.rank).T, bits)  # (r, M, K)
+        g_b = error_delta.moving_factor(
+            b_p & mask, g_flat.reshape(fac.rank, span), bits)    # (r, K, N)
     out = delta_gemm.delta_matmul_fused(a_p, b_p, f_a, g_b, bm=bm_, bn=bn_,
                                         bk=bk_, interpret=interpret)
     out = out[:m, :n]

@@ -73,7 +73,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
-from repro.core import abft
+from repro.core import abft, error_delta
 from repro.core.gemm import EXACT, GemmPolicy
 from repro.models import api as model_api
 from repro.train.fault import TransientError
@@ -1273,6 +1273,9 @@ class ServeEngine:
         out["queue_wait_mean_ms"] = round(
             occ["queue_wait_ns"] * 1e-6 / max(1, occ["admitted"]), 3)
         out.update(self.events)              # reliability counters
+        # bound approx_delta GEMM call sites traced in this process, per
+        # lookup form of the moving operand's factor (select / gather)
+        out["delta_forms"] = dict(error_delta.FORM_SITES)
         if self.paged:
             out.update({
                 # occupancy: fraction of slot-steps / pool-block-steps that

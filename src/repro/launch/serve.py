@@ -265,6 +265,9 @@ def main(argv=None):
             f"{p} {st[p + '_ms'] * per_step:.3f}"
             for p in ("admit", "prepare", "dispatch", "sync", "commit"))
             + f" ms/step; queue wait {st['queue_wait_mean_ms']:.3f} ms mean")
+        if st["delta_forms"]:
+            print("approx_delta lookup sites: " + ", ".join(
+                f"{form} {n}" for form, n in sorted(st["delta_forms"].items())))
         if args.paged:
             tok_total = max(1, st["prefill_tokens"] + st["decode_tokens"])
             print(f"occupancy: slots {st['slot_utilization']:.1%} "

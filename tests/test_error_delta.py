@@ -1,7 +1,10 @@
 """error_delta decomposition + approx_delta backend: bit-equality with the
 gather path (lut.lut_matmul) across shapes/ranks, rank selection, padding."""
-import numpy as np
+import dataclasses
+
+import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 try:
@@ -9,9 +12,12 @@ try:
 except ImportError:  # deterministic fallback, see _hypothesis_fallback.py
     from _hypothesis_fallback import given, settings, strategies as st
 
+from repro.configs import ARCHS, reduced
 from repro.core import error_delta, gemm, lut
 from repro.core.emulate import product_table
 from repro.kernels import ops
+from repro.launch import engine as E
+from repro.models import get_model
 
 
 def _rand(shape, rng, lo=-128, hi=128):
@@ -171,3 +177,99 @@ def test_dot_delta_close_to_float():
     ref = x @ w
     rel = float(jnp.abs(out - ref).mean() / jnp.abs(ref).mean())
     assert rel < 0.08, rel
+
+
+# --- the moving operand's factor lookup: selects on the low k bits ----------
+
+def _prepared_slices(w, *, side, kf, signed, stacked):
+    """(fixed operand, its PreparedDelta) pairs: one restricted preparation,
+    or every slice of one stacked ``restrict=False`` preparation."""
+    if not stacked:
+        return [(w, error_delta.prepare_delta(w, side=side, k=kf,
+                                              signed=signed))]
+    prep = error_delta.prepare_delta(w, side=side, k=kf, signed=signed,
+                                     restrict=False)
+    return [(w[i], jax.tree.map(lambda leaf, i=i: leaf[i], prep))
+            for i in range(w.shape[0])]
+
+
+@pytest.mark.parametrize("stacked", (False, True), ids=("restricted", "stacked"))
+@pytest.mark.parametrize("side", ("right", "left"))
+@pytest.mark.parametrize("signed", (True, False), ids=("signed", "unsigned"))
+@pytest.mark.parametrize("kf", (1, 2, 3, 4, 6))
+def test_lookup_paths_match_lut(kf, signed, side, stacked):
+    """Prepared, reference and Pallas-wrapper paths against the gather GEMM,
+    bit for bit, on both sides of the select threshold and on odd shapes."""
+    rng = np.random.default_rng(kf * 8 + signed * 4 + (side == "left") * 2
+                                + stacked)
+    lo, hi = (-128, 128) if signed else (0, 256)
+    m, kd, n = 13, 37, 11
+    stack = (2,) if stacked else ()
+    fixed_shape = (kd, n) if side == "right" else (m, kd)
+    w = _rand(stack + fixed_shape, rng, lo, hi)
+    x = _rand((m, kd) if side == "right" else (kd, n), rng, lo, hi)
+    for w2, prep in _prepared_slices(w, side=side, kf=kf, signed=signed,
+                                     stacked=stacked):
+        a, b = (x, w2) if side == "right" else (w2, x)
+        want = np.asarray(lut.lut_matmul(a, b, k=kf, signed=signed))
+        np.testing.assert_array_equal(
+            np.asarray(error_delta.delta_matmul_prepared(x, prep)), want)
+        np.testing.assert_array_equal(
+            np.asarray(error_delta.delta_matmul_ref(a, b, k=kf,
+                                                    signed=signed)), want)
+        np.testing.assert_array_equal(
+            np.asarray(ops.approx_delta_matmul(a, b, k=kf, signed=signed)),
+            want)
+
+
+@pytest.mark.parametrize("kf", (1, 2, 3, 4, 5, 6))
+@pytest.mark.parametrize("signed", (True, False), ids=("signed", "unsigned"))
+def test_select_lookup_equals_gather_bitwise(kf, signed):
+    """The generic factors repeat every 2^k patterns, so the select chain
+    returns the gather's float32 values bit for bit. Only the sign of a zero
+    may differ (the SVD leaves -0.0 in some repeats of an all-zero pattern),
+    which no sum and no rounding can see; adding +0.0 folds it away."""
+    fac = error_delta.delta_factors(8, kf, signed, 24)
+    rng = np.random.default_rng(kf)
+    x_u = jnp.asarray(np.concatenate([np.arange(256),
+                                      rng.integers(0, 256, 256)]).reshape(
+                                          16, 32), jnp.int32)
+    for tab in (jnp.asarray(fac.f.T), jnp.asarray(fac.g)):
+        sel = np.asarray(error_delta.moving_factor(x_u, tab, kf))
+        gat = np.asarray(error_delta.moving_factor(x_u, tab, None))
+        assert sel.shape == gat.shape == (fac.rank, 16, 32)
+        np.testing.assert_array_equal((sel + np.float32(0)).view(np.uint32),
+                                      (gat + np.float32(0)).view(np.uint32))
+
+
+def test_bound_lookup_lowers_without_gather_below_threshold():
+    """A bound k=2 weight at reduced chat-like shapes lowers with no gather;
+    a weight with more low-bit patterns than the threshold keeps it. The
+    form, the tally and the engine's ``delta_forms`` say the same."""
+    k_gather = next(kf for kf in range(1, 9)
+                    if (1 << kf) > error_delta.SELECT_MAX_PATTERNS)
+    rng = np.random.default_rng(14)
+    x = _rand((32, 96), rng)
+    for kf, form in ((2, "select"), (k_gather, "gather")):
+        prep = error_delta.prepare_delta(_rand((96, 64), rng), k=kf,
+                                         restrict=False)
+        assert prep.form == form
+        before = error_delta.FORM_SITES.copy()
+        hlo = jax.jit(error_delta.delta_matmul_prepared).lower(
+            x, prep).as_text()
+        assert ("gather" in hlo) == (form == "gather"), form
+        assert error_delta.FORM_SITES - before == {form: 1}
+
+    cfg = dataclasses.replace(reduced(ARCHS["smollm-360m"]),
+                              name="delta-forms-probe")
+    model = get_model(cfg)
+    pol = gemm.GemmPolicy(backend="approx_delta", k=2)
+    params = model.bind_params(model.init_params(jax.random.PRNGKey(0)), pol)
+    eng = E.ServeEngine(cfg, params, policy=pol, max_slots=2, max_len=16,
+                        block_size=4, prefill_chunk=4)
+    before = eng.stats["delta_forms"]
+    eng.run([E.Request(rid=0, prompt=np.arange(5, dtype=np.int32),
+                       max_new_tokens=3)])
+    after = eng.stats["delta_forms"]
+    traced = {f: after.get(f, 0) - before.get(f, 0) for f in after}
+    assert traced.get("select", 0) > 0 and not traced.get("gather"), traced
